@@ -110,4 +110,6 @@ def run(print_rows=True) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.engine.compile import enable_compile_cache
+    enable_compile_cache()
     run()

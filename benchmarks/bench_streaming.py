@@ -40,6 +40,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.core import MemoryStore, MetadataStore
+from repro.engine.compile import default_pallas_interpret, enable_compile_cache
 from repro.pipeline import Pipeline, RunOptions, Windowing
 from repro.streaming import StreamSource, StreamingCoordinator
 
@@ -381,7 +382,8 @@ def run(print_rows: bool = True,
     # (backend="pallas").  Recorded, not gated: off-TPU the kernel runs
     # under the pallas *interpreter*, so these rows track the dispatch
     # seam's cost trajectory, not the kernel's HBM win (that placement is
-    # benchmarks/roofline.py's streaming-fold table).
+    # benchmarks/roofline.py's streaming-fold table).  The pallas row says
+    # which of the two ran: ``interpret=True`` or ``interpret=False``.
     def run_fold_backend(job_id: str, backend: str):
         built = (Pipeline.from_source(records=events,
                                       batch_records=SLIDING_BATCH)
@@ -402,7 +404,8 @@ def run(print_rows: bool = True,
             rep_fb.mean_batch_latency * 1e6,
             f"records_per_s={rep_fb.records_per_sec:.0f};"
             f"windows={rep_fb.windows_emitted};"
-            + ("interpret=cpu" if backend == "pallas" else "jit=xla")))
+            + (f"interpret={default_pallas_interpret()}"
+               if backend == "pallas" else "jit=xla")))
     # the job-service lifecycle: cold-start latency (parked checkpoint →
     # running coordinator), the full scale-to-zero-and-back round trip
     # (event lands while the pool is at zero → its records are folded),
@@ -501,33 +504,6 @@ def run(print_rows: bool = True,
         f"duplicate_records_per_s={n_tenants * N_EVENTS / dup_wall:.0f};"
         f"speedup_vs_duplicate={dup_wall / shared_wall:.2f}x"))
 
-    # warm-pool vs forked-process worker cold start: the restore above
-    # reuses this process's interpreter, imports, and jit cache — the
-    # deployment alternative is a forked worker process that pays
-    # interpreter + JAX init before touching a record.  One honest
-    # subprocess measurement (python -c "import jax; one tiny op"), no
-    # amortization.  Recorded, not gated.
-    import subprocess
-    t0 = time.perf_counter()
-    subprocess.run(
-        [sys.executable, "-c",
-         "import jax.numpy as jnp; jnp.zeros((8,)).sum().block_until_ready()"],
-        check=True, capture_output=True)
-    forked_s = time.perf_counter() - t0
-    entry["job_service"]["worker_cold_start"] = {
-        "warm_pool_restore_ms": entry["job_service"]["cold_start_ms"],
-        "forked_process_ms": round(forked_s * 1e3, 3),
-        "warm_advantage": round(
-            forked_s * 1e3 / max(entry["job_service"]["cold_start_ms"],
-                                 1e-3), 1),
-    }
-    rows.append(fmt_csv(
-        "streaming/worker_cold_start", forked_s * 1e6,
-        f"warm_pool_restore_ms={entry['job_service']['cold_start_ms']};"
-        f"forked_process_ms={forked_s * 1e3:.1f};"
-        f"warm_advantage="
-        f"{entry['job_service']['worker_cold_start']['warm_advantage']}x"))
-
     # overlapped vs serial multi-tenant drive: the same three tenants on
     # one shared source, serial round-robin (overlap=False) vs the
     # overlapped per-job prepare/fold lanes — identical job ids and
@@ -578,6 +554,7 @@ def run(print_rows: bool = True,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("name,us_per_call,derived")
     _rows, _entry = run()
     if "--check" in sys.argv[1:]:

@@ -8,7 +8,9 @@ def main() -> None:
     from . import (bench_fig6_end_to_end, bench_fig7_components,
                    bench_fig8_phases, bench_kernels, bench_scaling,
                    bench_streaming)
+    from repro.engine.compile import enable_compile_cache
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for mod in (bench_fig6_end_to_end, bench_fig7_components,

@@ -168,4 +168,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.engine.compile import enable_compile_cache
+    enable_compile_cache()
     main()

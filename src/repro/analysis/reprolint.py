@@ -2,7 +2,7 @@
 
 The container ships no ruff plugin toolchain, so the invariants that keep
 this repo correct-by-construction are enforced by a small custom linter:
-the shard_map version shim must stay behind one chokepoint, the PR 6
+``shard_map`` must stay behind one chokepoint, the PR 6
 three-lane scheduler's byte-identity contract must hold (no host syncs in
 hot lanes, no shared-state mutation off its declared lane), SPMD bodies
 that get traced/``vmap``'d/``shard_map``'d must stay pure, and a donated
@@ -13,7 +13,7 @@ Rules (stable ids — tests pin them; all findings are error-level):
 ======  ====================================================================
 RL101   ``jax.experimental.shard_map`` / ``jax.shard_map`` imported or
         referenced outside ``engine/compile.py`` (all callers go through
-        ``make_shard_map`` — the version shim has one home)
+        ``make_shard_map`` — the one home of its options)
 RL102   host-sync call inside an ``@lane("driver")`` / ``@lane("prefetch")``
         function: ``jax.device_get``, ``np.asarray``, ``.block_until_ready``,
         ``.item()``, or ``int()``/``float()`` over a name in the module's
@@ -238,9 +238,9 @@ def lint_source(src: str, path: str) -> list:
                 for alias in node.names:
                     if alias.name.startswith("jax.experimental.shard_map"):
                         emit("RL101",
-                             f"import {alias.name}: shard_map is "
-                             f"version-gated behind "
-                             f"engine.compile.make_shard_map", node)
+                             f"import {alias.name}: route shard_map "
+                             f"through engine.compile.make_shard_map",
+                             node)
             elif isinstance(node, ast.ImportFrom):
                 mod = node.module or ""
                 if mod.startswith("jax.experimental.shard_map") or (

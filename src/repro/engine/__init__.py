@@ -8,7 +8,8 @@ workers or ``shard_map`` over a real mesh axis).  ``core.mapreduce`` and
 
 Layout: ``plan`` (the declarative vocabulary + compiled plan objects),
 ``stages`` (pure SPMD stage bodies: shuffle, window fan-out, key hashing,
-group buffers), ``compile`` (backend lowering + the jax version shim).
+group buffers), ``compile`` (backend lowering, the one ``shard_map``
+call site and the compile-cache helper).
 """
 
 from .compile import lower, make_shard_map

@@ -19,26 +19,19 @@ shard_map (flat) wire layout.  ``lower`` only knows enough about it to
 say so in its error; ``default_pallas_interpret`` is the one switch every
 pallas caller consults for compile-vs-interpret.
 
-This module also owns the JAX version shim: jax >= 0.5 exposes
-``jax.shard_map`` at top level with ``check_vma``; older releases (the
-container ships 0.4.x) keep it in ``jax.experimental`` with ``check_rep``.
-Callers (``core.mapreduce``, ``runtime.train_step``) must route through
-``make_shard_map`` instead of touching ``jax.shard_map`` directly — drop
-the shim here, and only here, when the toolchain moves to jax >= 0.5.
+This module is also the one place that calls ``jax.shard_map``: callers
+(``core.mapreduce``, ``runtime.train_step``) route through
+``make_shard_map`` (reprolint RL101 keeps it so), and the entry points
+place JAX's persistent compilation cache through ``enable_compile_cache``.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Any, Callable
 
 import jax
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_CHECK_KW = "check_vma"
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_CHECK_KW = "check_rep"
 
 
 #: backends ``ExecutionPlan.compile`` accepts; "pallas" is valid only for
@@ -53,13 +46,31 @@ def default_pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+#: where ``enable_compile_cache`` keeps compiled programs when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed path, since the path is
+#: part of the cache key and a directory that moves never hits
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (scripts, benchmarks, examples — never tests) and return its directory.
+    A ``JAX_COMPILATION_CACHE_DIR`` set outside wins and is left as JAX
+    reads it; otherwise the cache goes to ``<repo>/.jax_cache``."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_COMPILE_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def make_shard_map(body: Callable, mesh: jax.sharding.Mesh, in_specs,
                    out_specs) -> Callable:
-    """Version-portable ``shard_map`` with the replication checker off —
-    finalized outputs are all_gather/psum results, replicated by
-    construction, which the static checker can't always prove."""
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SM_CHECK_KW: False})
+    """``jax.shard_map`` with the replication checker off — finalized
+    outputs are all_gather/psum results, replicated by construction, which
+    the static checker can't always prove."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _vmap_axes(specs: Any, axis_name: str):

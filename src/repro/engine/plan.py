@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import stages
-from .compile import default_pallas_interpret, lower
+from .compile import lower
 from .stages import ShuffleStats
 
 P = jax.sharding.PartitionSpec
@@ -451,6 +451,15 @@ def _select_top_k(agg: jax.Array, num_buckets: int, k: int, kind: str):
     return stages.top_k_buckets(agg[:num_buckets], k, kind)
 
 
+def _carry_placement(backend: str, mesh, axis: str):
+    """Where a fresh carry lives: split over the mesh axis under shard_map,
+    as every step returns it (so the first step's donation is usable and
+    no device holds the whole slab); the default device otherwise."""
+    if backend == "shard_map":
+        return jax.sharding.NamedSharding(mesh, P(axis))
+    return None
+
+
 def _flat_carry(carry: jax.Array) -> tuple[jax.Array, tuple]:
     """View a (possibly vmap-batched) aggregate carry as its flattened
     (n_slots * num_buckets, channels) id space."""
@@ -534,6 +543,7 @@ class CompiledStreamAggregate:
                              "n_workers")
         self.plan = plan
         self.backend = backend
+        self._placement = _carry_placement(backend, mesh, plan.axis_name)
         self._per_worker = (ws.n_slots * carry_b) // plan.n_workers
         axis = plan.axis_name
         if backend == "pallas":
@@ -550,8 +560,7 @@ class CompiledStreamAggregate:
                 carry_buckets=carry_b,
                 channel_base=plan.reduce.channel_base,
                 hashed=plan.key_space.is_hashed,
-                host_wire=not ws.fanout_on_device,
-                interpret=default_pallas_interpret())
+                host_wire=not ws.fanout_on_device)
         else:
             if ws.fanout_on_device:
                 body = partial(_stream_agg_device_body, plan=plan)
@@ -580,7 +589,8 @@ class CompiledStreamAggregate:
             return jnp.zeros((plan.n_workers, self._per_worker, n_channels),
                              dtype)
         return jnp.zeros(
-            (plan.window.n_slots * plan.carry_buckets, n_channels), dtype)
+            (plan.window.n_slots * plan.carry_buckets, n_channels), dtype,
+            device=self._placement)
 
     def step(self, rows, carry, min_window: int | None = None, *,
              donate: bool = False):
@@ -729,6 +739,7 @@ class CompiledStreamGroup:
         self.plan = plan
         self.backend = backend
         axis = plan.axis_name
+        self._placement = _carry_placement(backend, mesh, axis)
         self._lower_step = partial(lower, partial(_stream_group_body,
                                                   plan=plan),
                                    axis_name=axis,
@@ -755,9 +766,10 @@ class CompiledStreamGroup:
             shape = (plan.n_workers, n_slots, cap)
         else:
             shape = (plan.n_workers * n_slots, cap)
-        return {"keys": jnp.full(shape, stages.INVALID, jnp.int32),
-                "vals": jnp.zeros(shape, dtype),
-                "counts": jnp.zeros(shape[:-1], jnp.int32)}
+        at = self._placement
+        return {"keys": jnp.full(shape, stages.INVALID, jnp.int32, device=at),
+                "vals": jnp.zeros(shape, dtype, device=at),
+                "counts": jnp.zeros(shape[:-1], jnp.int32, device=at)}
 
     def step(self, rows, carry, min_window: int | None = None, *,
              donate: bool = False):

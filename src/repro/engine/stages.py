@@ -271,9 +271,7 @@ def resolve_combine_fn(combine_fn):
     pipeline configs can name the kernel without importing it."""
     if combine_fn == "pallas":
         from ..kernels.hash_combine.ops import make_combine_fn
-        from .compile import default_pallas_interpret
-        return make_combine_fn(use_pallas=True,
-                               interpret=default_pallas_interpret())
+        return make_combine_fn(use_pallas=True)
     return combine_fn or local_combine_dense
 
 

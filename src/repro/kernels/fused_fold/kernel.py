@@ -29,9 +29,10 @@ three directions:
 
 Grid: ``(carry tiles, record tiles)`` — record tiles iterate innermost,
 so each carry tile stays resident in VMEM while every record tile streams
-past it.  VMEM per step: block_n·width rows + m·block_s one-hot +
-block_s·channels carry (m = block_n·fanout), fp32; defaults (block_n=256,
-fanout ≤ 8, carry tiles ≤ 4096 ids) stay well under 16 MB.
+past it.  The wire enters transposed, ``(width, block_n)``, one record per
+lane, and the fan-out is a static loop: copy j builds its own
+``(block_s, block_n)`` one-hot, so Mosaic never sees a reshape.
+``pick_block_s`` sizes the carry tile from a VMEM budget.
 """
 
 from __future__ import annotations
@@ -45,6 +46,29 @@ from jax.experimental import pallas as pl
 from .ref import FOLD_KINDS, HOST_ROW, DEVICE_ROW
 
 INT32_MIN = -(2 ** 31)
+
+#: VMEM bytes one grid step may plan for: under the 16 MiB default scoped
+#: limit of a TPU v5e core, with room left for Mosaic's own scratch
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def pick_block_s(size: int, channels: int, block_n: int) -> int:
+    """The carry tile: the largest divisor of ``size`` (the flat id space,
+    ``n_slots * carry_buckets``) that is a multiple of 8 and whose VMEM
+    footprint fits ``VMEM_BUDGET``.  Per carry row a step holds the carry block
+    in and out, double-buffered, and the accumulator and update
+    temporaries, each padded to 128 lanes, plus about four ``block_n``-wide
+    f32 one-hot temporaries."""
+    lanes = -(-channels // 128) * 128
+    per_row = 4 * (8 * lanes + 4 * block_n)
+    cap = VMEM_BUDGET // per_row
+    if size <= cap:
+        return size
+    for d in range(cap - cap % 8, 7, -8):
+        if size % d == 0:
+            return d
+    raise ValueError(f"no carry tile fits VMEM: n_slots * carry_buckets = "
+                     f"{size} has no multiple-of-8 divisor <= {cap}")
 
 
 def _bucketize(keys, num_buckets: int, hashed: bool):
@@ -76,77 +100,88 @@ def _fused_fold_kernel(rows_ref, carry_ref, minw_ref, out_ref, stats_ref, *,
     def _zero_stats():
         stats_ref[...] = jnp.zeros_like(stats_ref)
 
-    rows = rows_ref[...]            # (block_n, width) float32 wire rows
-    n = rows.shape[0]
+    # (width, block_n) transposed wire: one record per lane, so every field
+    # is a lane-dense (1, block_n) row and nothing below needs a reshape
+    rows = rows_ref[...]
+    n = rows.shape[1]
 
     # -- decode + fan-out + watermark masking (registers, no HBM traffic) --
+    # ``pairs`` holds one (flat id, live) row pair per fan-out copy j
     if host_wire:                   # host already expanded; fan-out 1
-        slot = rows[:, 0].astype(jnp.int32)[:, None]
-        bucket = _bucketize(rows[:, 1], num_buckets, hashed)[:, None]
-        val = rows[:, 2][:, None]
-        live = (rows[:, 3] > 0)[:, None]
+        slot = rows[0:1].astype(jnp.int32)
+        bucket = _bucketize(rows[1:2], num_buckets, hashed)
+        val = rows[2:3]
+        pairs = [(slot * carry_buckets + bucket, rows[3:4] > 0)]
         late = jnp.zeros((), jnp.int32)
     else:
-        last = rows[:, 0].astype(jnp.int32)
-        n_windows = rows[:, 1].astype(jnp.int32)
-        bucket = _bucketize(rows[:, 2], num_buckets, hashed)[:, None]
-        val = rows[:, 3][:, None]
-        valid = rows[:, 4] > 0
-        j = jax.lax.broadcasted_iota(jnp.int32, (n, fanout), 1)
-        widx = last[:, None] - j
-        covers = valid[:, None] & (j < n_windows[:, None])
+        last = rows[0:1].astype(jnp.int32)
+        n_windows = rows[1:2].astype(jnp.int32)
+        bucket = _bucketize(rows[2:3], num_buckets, hashed)
+        val = rows[3:4]
+        valid = rows[4:5] > 0
         minw = minw_ref[0, 0]
-        live = covers & (widx >= minw)
-        late = jnp.sum((covers & (widx < minw)).astype(jnp.int32))
-        slot = jnp.mod(widx, n_slots)
-    flat = slot * carry_buckets + bucket            # (n, F) fan-out pairs
-    flat = jnp.where(live, flat, -1)                # dead pair → no one-hot
-    folded = jnp.sum(live.astype(jnp.int32))
+        pairs, late = [], jnp.zeros((), jnp.int32)
+        for j in range(fanout):     # static: copy j covers window last - j
+            widx = last - j
+            covers = valid & (j < n_windows)
+            late += jnp.sum((covers & (widx < minw)).astype(jnp.int32))
+            slot = jnp.mod(widx, n_slots)
+            pairs.append((slot * carry_buckets + bucket,
+                          covers & (widx >= minw)))
+    folded = sum(jnp.sum(live.astype(jnp.int32)) for _, live in pairs)
 
-    m = n * (1 if host_wire else fanout)
-    rel = flat.reshape(m, 1) - s * block_s          # id within this tile
-    ids = jax.lax.broadcasted_iota(jnp.int32, (m, block_s), 1)
-    hit = rel == ids                                # (m, block_s) one-hot
-    valf = jnp.broadcast_to(val, (n, m // n)).reshape(m, 1)
+    # flat id of each carry row in this tile, against every record lane;
+    # a dead pair's id is −1, whose one-hot column is all zeros.  One
+    # (block_s, n) one-hot per fan-out copy, consumed before the next
+    ids = jax.lax.broadcasted_iota(jnp.int32, (block_s, n), 0) + s * block_s
     channels = out_ref.shape[1]
     ch = jax.lax.broadcasted_iota(jnp.int32, (1, channels), 1)
 
     if kind in ("sum", "count"):
-        onehot = hit.astype(jnp.float32)
-        contrib = jnp.ones((m, 1), jnp.float32) if kind == "count" else valf
-        # [Σ value-or-one, Σ 1] per flat id — one (block_s × m)·(m × 2)
-        # matmul on the MXU; dead pairs have all-zero one-hot rows
-        pair = jnp.concatenate([contrib, jnp.ones((m, 1), jnp.float32)],
-                               axis=1)
-        acc = jax.lax.dot_general(onehot, pair, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+        # [Σ value-or-one, Σ 1] per flat id: one (block_s × n)·(n × 2)
+        # matmul on the MXU per fan-out copy.  HIGHEST keeps the products
+        # in f32 (a bf16 pass would round values), so the sums match the
+        # XLA backends' bytes wherever f32 addition is exact
+        contrib = jnp.ones_like(val) if kind == "count" else val
+        pair = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (2, n), 0) == 0,
+                         contrib, 1.0)
+        acc = jnp.zeros((block_s, 2), jnp.float32)
+        for flat, live in pairs:
+            onehot = (ids == jnp.where(live, flat, -1)).astype(jnp.float32)
+            acc += jax.lax.dot_general(
+                onehot, pair, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
         out_ref[...] += (
             jnp.where(ch == channel_base, acc[:, 0:1], 0.0)
             + jnp.where(ch == channel_base + 1, acc[:, 1:2], 0.0)
         ).astype(out_ref.dtype)
     else:                           # min / max: masked running extremum
         neutral = jnp.float32(jnp.inf if kind == "min" else -jnp.inf)
-        cand = jnp.where(hit, jnp.broadcast_to(valf, (m, block_s)), neutral)
-        ext = cand.min(axis=0) if kind == "min" else cand.max(axis=0)
-        cnt = jnp.sum(hit.astype(jnp.float32), axis=0)
+        pick = jnp.minimum if kind == "min" else jnp.maximum
+        reduce = jnp.min if kind == "min" else jnp.max
+        ext = jnp.full((block_s, 1), neutral)
+        cnt = jnp.zeros((block_s, 1), jnp.float32)
+        for flat, live in pairs:
+            hit = ids == jnp.where(live, flat, -1)
+            ext = pick(ext, reduce(jnp.where(hit, val, neutral), axis=1,
+                                   keepdims=True))
+            cnt += jnp.sum(hit.astype(jnp.float32), axis=1, keepdims=True)
         old = out_ref[...]
-        old_v = old[:, channel_base]
-        old_c = old[:, channel_base + 1]
-        eff = jnp.where(old_c > 0, old_v, neutral)
-        comb = jnp.minimum(eff, ext) if kind == "min" \
-            else jnp.maximum(eff, ext)
+        old_v = old[:, channel_base:channel_base + 1]
+        old_c = old[:, channel_base + 1:channel_base + 2]
+        comb = pick(jnp.where(old_c > 0, old_v, neutral), ext)
         new_c = old_c + cnt
         new_v = jnp.where(new_c > 0, comb, 0.0)
         out_ref[...] = jnp.where(
-            ch == channel_base, new_v[:, None],
-            jnp.where(ch == channel_base + 1, new_c[:, None], old)
+            ch == channel_base, new_v,
+            jnp.where(ch == channel_base + 1, new_c, old)
         ).astype(out_ref.dtype)
 
     @pl.when(s == 0)                # each record tile counted exactly once
     def _count():
-        stats_ref[...] += jnp.concatenate(
-            [late.reshape(1, 1), folded.reshape(1, 1),
-             jnp.zeros((1, 1), jnp.int32)], axis=1)
+        k = jax.lax.broadcasted_iota(jnp.int32, (1, 3), 1)
+        stats_ref[...] += jnp.where(k == 0, late, jnp.where(k == 1, folded, 0))
 
 
 @functools.partial(
@@ -167,8 +202,9 @@ def fused_streaming_fold(rows, carry, min_window=None, *, fanout: int,
     key, value, valid]`` (or (N, 4) host wire ``[window_slot, key, value,
     valid]`` with ``host_wire=True``); carry : the flattened
     ``(n_slots * carry_buckets, channels)`` slab.  N pads to ``block_n``
-    internally (pad rows are invalid); ``block_s`` tiles the flat id space
-    (default: one resident tile).  Bit-parity oracle:
+    (a multiple of 128 when compiled) internally (pad rows are invalid);
+    ``block_s`` tiles the flat id space (default: ``pick_block_s``, the
+    largest tile that fits the VMEM budget).  Bit-parity oracle:
     ``ref.fused_streaming_fold_ref``.
     """
     if kind not in FOLD_KINDS:
@@ -181,9 +217,10 @@ def fused_streaming_fold(rows, carry, min_window=None, *, fanout: int,
     if rows.shape[1] != width:
         raise ValueError(f"expected width-{width} wire rows, got "
                          f"{rows.shape}")
-    block_s = block_s or size
-    if size % block_s:
-        raise ValueError("block_s must divide n_slots * carry_buckets")
+    block_s = block_s or pick_block_s(size, channels, block_n)
+    if size % block_s or (block_s % 8 and block_s != size):
+        raise ValueError(f"block_s={block_s} must be a multiple of 8 that "
+                         f"divides n_slots * carry_buckets = {size}")
     minw = INT32_MIN if min_window is None else min_window
     minw = jnp.asarray(minw, jnp.int32).reshape(1, 1)
 
@@ -191,6 +228,7 @@ def fused_streaming_fold(rows, carry, min_window=None, *, fanout: int,
     n_pad = (-n) % block_n
     if n_pad:                       # zero rows decode as invalid
         rows = jnp.pad(rows, ((0, n_pad), (0, 0)))
+    rows = rows.T                   # (width, N): records along the lanes
     grid = (size // block_s, (n + n_pad) // block_n)
 
     new_carry, stats = pl.pallas_call(
@@ -201,7 +239,7 @@ def fused_streaming_fold(rows, carry, min_window=None, *, fanout: int,
             kind=kind, block_s=block_s),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n, width), lambda s, i: (i, 0)),
+            pl.BlockSpec((width, block_n), lambda s, i: (0, i)),
             pl.BlockSpec((block_s, channels), lambda s, i: (s, 0)),
             pl.BlockSpec((1, 1), lambda s, i: (0, 0)),
         ],

@@ -2,10 +2,8 @@
 
 ``fold(..., use_pallas=False)`` routes to the XLA segment-sum reference
 (``ref.py``); ``use_pallas=True`` targets the Pallas kernel.  ``interpret``
-defaults to auto: compiled lowering on TPU, interpret mode (the kernel body
-as jax ops) everywhere else — the switch every kernel caller in the engine
-routes through, so one env answers "can this host run Mosaic?" in one
-place.
+defaults to ``engine.compile.default_pallas_interpret``: compiled lowering
+on TPU, interpret mode (the kernel body as jax ops) everywhere else.
 
 ``make_fold_step`` builds the streaming-step callable
 ``CompiledStreamAggregate`` dispatches to for ``backend="pallas"``: the
@@ -20,13 +18,9 @@ from __future__ import annotations
 
 import jax
 
+from ...engine.compile import default_pallas_interpret
 from .kernel import fused_streaming_fold
 from .ref import fused_streaming_fold_ref
-
-
-def default_interpret() -> bool:
-    """Interpret the kernel body unless a real TPU can compile it."""
-    return jax.default_backend() != "tpu"
 
 
 def fold(rows, carry, min_window=None, *, fanout, n_slots, num_buckets,
@@ -35,7 +29,7 @@ def fold(rows, carry, min_window=None, *, fanout, n_slots, num_buckets,
          block_s=None):
     if use_pallas:
         if interpret is None:
-            interpret = default_interpret()
+            interpret = default_pallas_interpret()
         return fused_streaming_fold(
             rows, carry, min_window, fanout=fanout, n_slots=n_slots,
             num_buckets=num_buckets, carry_buckets=carry_buckets,

@@ -41,18 +41,20 @@ def _hash_combine_kernel(keys_ref, values_ref, valid_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    keys = keys_ref[...]          # (block_n,)
+    keys = keys_ref[...]          # (block_n, 1)
     vals = values_ref[...]        # (block_n, D)
-    valid = valid_ref[...]        # (block_n,)
+    valid = valid_ref[...]        # (block_n, 1)
 
-    # one-hot via iota comparison — MXU-friendly, no gather/scatter
+    # one-hot via iota comparison — MXU-friendly, no gather/scatter; an
+    # invalid record's key is −1, whose one-hot row is all zeros
     buckets = jax.lax.broadcasted_iota(jnp.int32, (keys.shape[0], num_buckets), 1)
-    onehot = (keys[:, None] == buckets).astype(vals.dtype)
-    onehot = onehot * valid[:, None].astype(vals.dtype)
+    onehot = (jnp.where(valid != 0, keys, -1) == buckets).astype(vals.dtype)
 
-    # (B, block_n) @ (block_n, D) on the MXU, accumulated in fp32
+    # (B, block_n) @ (block_n, D) on the MXU, accumulated in fp32; HIGHEST
+    # keeps f32 values unrounded, as the dense jnp combiner does
     out_ref[...] += jax.lax.dot_general(
         onehot, vals, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
@@ -73,11 +75,14 @@ def hash_combine(keys: jax.Array, values: jax.Array,
     if valid is None:
         valid = jnp.ones((n,), dtype=jnp.bool_)
 
+    # keys and valid ride as (N, 1) columns: Mosaic keeps every block 2-D
+    keys = keys.astype(jnp.int32).reshape(n, 1)
+    valid = valid.astype(jnp.int32).reshape(n, 1)
     n_pad = (-n) % block_n
     if n_pad:
-        keys = jnp.pad(keys, (0, n_pad))
+        keys = jnp.pad(keys, ((0, n_pad), (0, 0)))
         values = jnp.pad(values, ((0, n_pad), (0, 0)))
-        valid = jnp.pad(valid, (0, n_pad))
+        valid = jnp.pad(valid, ((0, n_pad), (0, 0)))
     n_total = n + n_pad
     grid = (n_total // block_n,)
 
@@ -85,9 +90,9 @@ def hash_combine(keys: jax.Array, values: jax.Array,
         functools.partial(_hash_combine_kernel, num_buckets=num_buckets),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((num_buckets, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_buckets, d), values.dtype),

@@ -111,8 +111,7 @@ def make_shardmap_train_step(cfg: ModelConfig, opt: AdamW,
             {**metrics, **stats}
 
     def train_step(state, batch):
-        # routed through the engine's version shim: jax 0.4.x has no
-        # top-level jax.shard_map (see engine/compile.py)
+        # shard_map lives behind engine/compile.py (reprolint RL101)
         fn = make_shard_map(
             worker, mesh,
             (jax.tree.map(lambda _: P(), state),

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention.kernel import flash_attention, flash_decode
-from repro.kernels.fused_fold.kernel import fused_streaming_fold
+from repro.kernels.fused_fold.kernel import fused_streaming_fold, pick_block_s
 from repro.kernels.fused_fold.ref import fused_streaming_fold_ref
 from repro.kernels.flash_attention.ops import chunked_attention
 from repro.kernels.flash_attention.ref import decode_ref, mha_ref
@@ -258,6 +258,30 @@ def test_fused_fold_tiling_invariance():
             ref = (np.asarray(c), np.asarray(s))
         assert np.array_equal(np.asarray(c), ref[0]), (block_n, block_s)
         assert np.array_equal(np.asarray(s), ref[1]), (block_n, block_s)
+
+
+@pytest.mark.parametrize("size,channels,want", [
+    (144, 2, 144),                 # a small carry is one resident tile
+    (8 * 131072, 2, 1024),         # the deployment carry: a VMEM-sized tile
+    (8 * 131072, 6, 1024),         # a shared join carry pads to 128 lanes too
+    (6 * 100001 * 8, 2, 528),      # largest multiple-of-8 divisor under the cap
+])
+def test_fused_fold_carry_tile(size, channels, want):
+    """The carry tile divides the flat id space, is a multiple of 8 when
+    it is not the whole carry, and is the largest such under the budget."""
+    assert pick_block_s(size, channels, 256) == want
+
+
+def test_fused_fold_carry_tile_rejects():
+    """No tile fits: a large carry without a multiple-of-8 divisor under
+    the budget; and an explicit tile Mosaic could not lay out."""
+    with pytest.raises(ValueError, match="no carry tile fits"):
+        pick_block_s(6 * 100001, 2, 256)
+    rows = jnp.zeros((8, 5), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        fused_streaming_fold(rows, jnp.zeros((144, 2), jnp.float32),
+                             fanout=1, n_slots=6, num_buckets=24,
+                             carry_buckets=24, block_s=12, interpret=True)
 
 
 def test_fused_fold_in_kernel_hash_matches_engine():

@@ -14,12 +14,7 @@ from repro.models import init_params
 from repro.optim import AdamW
 from repro.runtime.train_step import init_train_state
 
-try:
-    # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-    MESH = AbstractMesh((16, 16), ("data", "model"))
-except TypeError:
-    # jax 0.4.x: AbstractMesh(((name, size), ...)) pair form
-    MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def _specs(arch):
