@@ -440,14 +440,14 @@ def run(print_rows: bool = True,
                     segment_records=4096)
     server.step()               # pump + cold restore + fold the new tail
     back_s = time.perf_counter() - t_zero
-    job = server.jobs[jid]
-    cold_s = job.cold_start_latencies[-1] if job.cold_start_latencies else 0.0
+    cold_s = server.status(jid)["cold_start_seconds"]   # the one restore
     server.run_until_complete()
+    status = server.status(jid)
     entry["job_service"] = {
         "cold_start_ms": round(cold_s * 1e3, 3),
         "scale_to_zero_and_back_ms": round(back_s * 1e3, 3),
-        "parks": server.registry.record(jid)["parks"],
-        "restores": server.registry.record(jid)["restores"],
+        "parks": status["parks"],
+        "restores": status["restores"],
     }
     rows.append(fmt_csv(
         "streaming/job_cold_start", cold_s * 1e6,

@@ -37,6 +37,7 @@ import heapq
 from itertools import islice
 from typing import Iterable, Iterator, Sequence
 
+from ..core import tracing
 from ..core.events import CloudEvent, EventBus, Record, ingest_topic
 from ..core.storage import ObjectStore
 from ..streaming.source import StreamSource
@@ -94,12 +95,16 @@ class SharedIngest:
         a crashed server re-materializes the identical layout) and carry
         their global ``seq``.  Returns new records appended."""
         n = 0
-        for rec in self.source.events_from(self.pumped):
-            self.bus.produce(self.topic,
-                             _record_event(self.source_id, rec,
-                                           self.pumped + n),
-                             key=str(rec[1]))
-            n += 1
+        with tracing.span("ingest.pump", key=self.source_id) as pump:
+            for seg, records in self.source.segments_from(self.pumped):
+                with tracing.span("ingest.publish", n=len(records), key=seg):
+                    for rec in records:
+                        self.bus.produce(self.topic,
+                                         _record_event(self.source_id, rec,
+                                                       self.pumped + n),
+                                         key=str(rec[1]))
+                        n += 1
+            pump.n = n
         self.pumped += n
         self.pumps += 1
         return n
@@ -189,6 +194,8 @@ class SubscriberSource(StreamSource):
     shares it — or whether its view is the whole topic or a partition
     slice.
     """
+
+    read_span = "topic.read"
 
     def __init__(self, ingest: SharedIngest, subscriber_id: str, *,
                  batch_records: int = 1024,

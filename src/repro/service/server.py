@@ -55,6 +55,7 @@ from dataclasses import dataclass, field
 from typing import Any, Iterable
 
 from ..analysis.diagnostics import PlanRejected, errors
+from ..core import tracing
 from ..core.autoscaler import (AutoscalerConfig, ComputeMeter, MeteredPool,
                                ServerlessPool)
 from ..core.events import (TOPIC_JOB_LIFECYCLE, EventBus,
@@ -133,7 +134,6 @@ class _Job:
     idle_since: float | None = None     # monotonic time the backlog emptied
     meter: ComputeMeter = field(default_factory=ComputeMeter)
     error: str | None = None
-    cold_start_latencies: list = field(default_factory=list)
 
     def __post_init__(self) -> None:
         if self.report is None:
@@ -311,27 +311,29 @@ class JobServer:
         tail — overlapped across jobs when more than one has backlog and
         ``overlap`` is on — and park the idle.  Returns records moved
         (pumped + folded) — 0 means quiescent."""
-        moved = 0
-        for ingest in self.ingests.values():
-            moved += ingest.pump()
-        runnable: list[_Job] = []
-        for job in list(self.jobs.values()):
-            if job.state == JobStatus.PARKED \
-                    and job.sub.lag(job.cursor) > job.park_policy.max_lag:
-                self._restore(job, verb="restored")
-            if job.state in (JobStatus.PENDING, JobStatus.RUNNING):
-                runnable.append(job)
-        lagging = [j for j in runnable
-                   if j.sub.lag(j.cursor) > j.park_policy.max_lag]
-        if self.overlap and len(lagging) > 1:
-            moved += self._drive_overlapped(lagging)
-            lagging_ids = {j.job_id for j in lagging}
-            rest = [j for j in runnable if j.job_id not in lagging_ids]
-        else:
-            rest = runnable
-        for job in rest:
-            if job.state in (JobStatus.PENDING, JobStatus.RUNNING):
-                moved += self._drive(job)
+        with tracing.span("server.step") as round_span:
+            moved = 0
+            for ingest in self.ingests.values():
+                moved += ingest.pump()
+            runnable: list[_Job] = []
+            for job in list(self.jobs.values()):
+                if job.state == JobStatus.PARKED \
+                        and job.sub.lag(job.cursor) > job.park_policy.max_lag:
+                    self._restore(job, verb="restored")
+                if job.state in (JobStatus.PENDING, JobStatus.RUNNING):
+                    runnable.append(job)
+            lagging = [j for j in runnable
+                       if j.sub.lag(j.cursor) > j.park_policy.max_lag]
+            if self.overlap and len(lagging) > 1:
+                moved += self._drive_overlapped(lagging)
+                lagging_ids = {j.job_id for j in lagging}
+                rest = [j for j in runnable if j.job_id not in lagging_ids]
+            else:
+                rest = runnable
+            for job in rest:
+                if job.state in (JobStatus.PENDING, JobStatus.RUNNING):
+                    moved += self._drive(job)
+            round_span.n = moved
         return moved
 
     def run_until_complete(self, flush: bool = True) -> dict[str, str]:
@@ -400,25 +402,27 @@ class JobServer:
 
     def _restore(self, job: _Job, *, verb: str) -> None:
         """Build (or cold-rebuild) the job's coordinator and restore its
-        checkpoint.  Timed end to end — pool activation, carry download,
-        tracker/dictionary rebuild — because this *is* the serverless
-        cold start the lifecycle trades against idle cost.  The
-        coordinator folds through a per-job ``MeteredPool`` view of the
-        one shared pool, so its compute bills to this job alone."""
+        checkpoint.  A cold restore is timed end to end — pool
+        activation, carry download, tracker/dictionary rebuild — into the
+        registry's ``restores`` / ``cold_start_seconds``, because this
+        *is* the serverless cold start the lifecycle trades against idle
+        cost.  The coordinator folds through a per-job ``MeteredPool``
+        view of the one shared pool, so its compute bills to this job
+        alone."""
         cold = job.state in (JobStatus.PARKED, JobStatus.PAUSED)
-        t0 = time.perf_counter()
-        self.pool.ensure_scale(1)
-        job.coord = StreamingCoordinator(
-            job.store, self.meta, bus=self.bus, program=job.program,
-            options=job.options, pool=MeteredPool(self.pool, job.meter))
-        job.cursor = job.coord.restore_state()
-        dt = time.perf_counter() - t0
-        job.idle_since = None
-        if cold:
-            job.cold_start_latencies.append(dt)
-            self.registry.bump(job.job_id, "restores")
-            self.registry.bump(job.job_id, "cold_start_seconds", dt)
-        self._transition(job, JobStatus.RUNNING, verb=verb)
+        with tracing.span("server.restore", key=job.job_id):
+            t0 = time.perf_counter()
+            self.pool.ensure_scale(1)
+            job.coord = StreamingCoordinator(
+                job.store, self.meta, bus=self.bus, program=job.program,
+                options=job.options, pool=MeteredPool(self.pool, job.meter))
+            job.cursor = job.coord.restore_state()
+            dt = time.perf_counter() - t0
+            job.idle_since = None
+            if cold:
+                self.registry.bump(job.job_id, "restores")
+                self.registry.bump(job.job_id, "cold_start_seconds", dt)
+            self._transition(job, JobStatus.RUNNING, verb=verb)
 
     def _drive(self, job: _Job, park_when_idle: bool = True) -> int:
         """Fold the job's currently-available tail, batch by batch, at
@@ -487,7 +491,9 @@ class JobServer:
                 for lane in lanes:
                     job, batches, prefetch = lane
                     try:
-                        prep = next(batches)
+                        with tracing.span("server.lane_wait",
+                                          key=job.job_id):
+                            prep = next(batches)
                     except StopIteration:
                         prefetch.close()
                         continue
@@ -534,11 +540,12 @@ class JobServer:
         """Scale-to-zero: checkpoint at the barrier, drop the coordinator
         (frees the device carries), retire pool instances if nothing else
         runs.  The job's next matching event cold-restores it."""
-        self._checkpoint(job)
-        job.coord = None
-        self.registry.bump(job.job_id, "parks")
-        self._transition(job, JobStatus.PARKED, verb="parked")
-        self._maybe_scale_to_zero()
+        with tracing.span("server.park", key=job.job_id):
+            self._checkpoint(job)
+            job.coord = None
+            self.registry.bump(job.job_id, "parks")
+            self._transition(job, JobStatus.PARKED, verb="parked")
+            self._maybe_scale_to_zero()
 
     def _fail(self, job: _Job, exc: Exception) -> None:
         job.error = f"{type(exc).__name__}: {exc}"

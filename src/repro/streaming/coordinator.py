@@ -110,6 +110,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.lanes import lane
+from ..core import tracing
 from ..core.autoscaler import AutoscalerConfig, ServerlessPool
 from ..core.events import (EventBus, TOPIC_STREAM_BATCH, TOPIC_STREAM_WINDOW,
                            batch_event, window_event)
@@ -665,7 +666,7 @@ class StreamingCoordinator:
 
     @lane("driver")
     def _fold_device(self, si: int, rows: np.ndarray, report: StreamReport,
-                     side: int = 0) -> None:
+                     side: int = 0, shipped: int = 0) -> None:
         """Fold one-row-per-record [last_window, n_windows, key, value,
         valid] rows through one side's compiled step; the device fans out,
         masks late pairs against the watermark bound, and returns the
@@ -673,12 +674,13 @@ class StreamingCoordinator:
         ``window_base`` (a multiple of ``n_slots``, so modular slots are
         unchanged) to stay exact in float32 at any absolute event time."""
         stage = self.stages[si]
-        data = self._wire(stage, rows, 5)
-        bound = stage.tracker.min_admissible() - stage.window_base
-        bound = max(min(bound, 2 ** 31 - 1), -(2 ** 31))
-        stage.carry, stats = self.pool.submit(
-            stage.plan.sides[side].compiled.step, data, stage.carry, bound,
-            donate=self.opts.donate_carry)
+        with tracing.span("coord.fold", n=shipped, key=self.prog.job_id):
+            data = self._wire(stage, rows, 5)
+            bound = stage.tracker.min_admissible() - stage.window_base
+            bound = max(min(bound, 2 ** 31 - 1), -(2 ** 31))
+            stage.carry, stats = self.pool.submit(
+                stage.plan.sides[side].compiled.step, data, stage.carry,
+                bound, donate=self.opts.donate_carry)
         self._account_stats(si, stats, report)
 
     @lane("driver")
@@ -707,21 +709,24 @@ class StreamingCoordinator:
         if not self._pending_stats:
             return
         pending, self._pending_stats = self._pending_stats, []
-        for si, stats in pending:
-            late, expanded, dropped = (int(x) for x in np.asarray(stats))
+        with tracing.span("coord.device_wait", key=self.prog.job_id):
+            read = [np.asarray(stats) for _si, stats in pending]
+        for (si, _stats), counts in zip(pending, read):
+            late, expanded, dropped = (int(x) for x in counts)
             self.stages[si].tracker.note_late(late)
             report.records_expanded += expanded
             report.capacity_dropped += dropped
 
     @lane("driver")
-    def _fold_host(self, si: int, rows: np.ndarray) -> None:
+    def _fold_host(self, si: int, rows: np.ndarray, shipped: int = 0) -> None:
         """Host-wire fold: [window_slot, key, value, valid] rows whose slot
         was assigned host-side (legacy host fan-out, or session cells)."""
         stage = self.stages[si]
-        data = self._wire(stage, rows, 4)
-        stage.carry, _ = self.pool.submit(stage.compiled.step, data,
-                                          stage.carry,
-                                          donate=self.opts.donate_carry)
+        with tracing.span("coord.fold", n=shipped, key=self.prog.job_id):
+            data = self._wire(stage, rows, 4)
+            stage.carry, _ = self.pool.submit(stage.compiled.step, data,
+                                              stage.carry,
+                                              donate=self.opts.donate_carry)
 
     # -- window finalization --------------------------------------------------
     @lane("driver")
@@ -1076,13 +1081,16 @@ class StreamingCoordinator:
         them (each fold's stats read is deferred to the drain lane), so
         independent branches of the DAG execute concurrently under JAX
         async dispatch instead of serializing on per-branch host reads."""
-        for si in range(len(self.stages)):
-            if si not in touched:
-                continue
-            for dst in self._finalize_stage(si, report):
-                self._observe(dst)
-                touched.add(dst)
-        self._flush_sinks(report)
+        emitted = report.windows_emitted
+        with tracing.span("coord.finalize", key=self.prog.job_id) as sweep:
+            for si in range(len(self.stages)):
+                if si not in touched:
+                    continue
+                for dst in self._finalize_stage(si, report):
+                    self._observe(dst)
+                    touched.add(dst)
+            self._flush_sinks(report)
+            sweep.n = report.windows_emitted - emitted
 
     # -- checkpoint / restore --------------------------------------------------
     @lane("barrier")
@@ -1112,22 +1120,28 @@ class StreamingCoordinator:
                 f"({len(self._pending_puts)} staged sink writes, "
                 f"{len(self._pending_stats)} deferred stats reads); "
                 "checkpoints must follow the batch-boundary drain")
-        carries = tuple(st.carry for st in self.stages)
-        leaves = [np.asarray(leaf)
-                  for leaf in jax.tree_util.tree_leaves(carries)]
-        buf = io.BytesIO()
-        np.savez(buf, **{f"leaf{i}": leaf for i, leaf in enumerate(leaves)})
-        self.store.put(_carry_key(self.prog.job_id), buf.getvalue())
-        self.meta.set(_state_key(self.prog.job_id), {
-            "offset": self._records_consumed,
-            "carry_shapes": [list(leaf.shape) for leaf in leaves],
-            "edge_fed": [e.fed for e in self.edges],
-            "stages": [{
-                "tracker": st.tracker.state_dict(),
-                "tables": [t.state_dict()
-                           for t in self._unique_tables(st)],
-            } for st in self.stages],
-        })
+        job_id = self.prog.job_id
+        with tracing.span("coord.checkpoint", key=job_id) as ckpt:
+            carries = tuple(st.carry for st in self.stages)
+            with tracing.span("coord.device_wait", key=job_id):
+                leaves = [np.asarray(leaf)
+                          for leaf in jax.tree_util.tree_leaves(carries)]
+            buf = io.BytesIO()
+            np.savez(buf, **{f"leaf{i}": leaf
+                             for i, leaf in enumerate(leaves)})
+            blob = buf.getvalue()
+            self.store.put(_carry_key(job_id), blob)
+            self.meta.set(_state_key(job_id), {
+                "offset": self._records_consumed,
+                "carry_shapes": [list(leaf.shape) for leaf in leaves],
+                "edge_fed": [e.fed for e in self.edges],
+                "stages": [{
+                    "tracker": st.tracker.state_dict(),
+                    "tables": [t.state_dict()
+                               for t in self._unique_tables(st)],
+                } for st in self.stages],
+            })
+            ckpt.n = len(blob)
 
     # back-compat private name (pre-PR 8 callers)
     _save_state = save_state
@@ -1215,12 +1229,14 @@ class StreamingCoordinator:
         record counts only (``batch_sizes``), so the log's payloads are
         parsed once — by the processing loop, not here."""
         n = 0
-        for index, size in enumerate(source.batch_sizes(start_record)):
-            self.bus.produce(
-                TOPIC_STREAM_BATCH,
-                batch_event(self.prog.job_id, index, size),
-                key=f"{self.prog.job_id}/{index}")
-            n += 1
+        with tracing.span("coord.announce", key=self.prog.job_id) as ann:
+            for index, size in enumerate(source.batch_sizes(start_record)):
+                self.bus.produce(
+                    TOPIC_STREAM_BATCH,
+                    batch_event(self.prog.job_id, index, size),
+                    key=f"{self.prog.job_id}/{index}")
+                n += 1
+            ann.n = n
         return n
 
     @lane("driver")
@@ -1267,7 +1283,7 @@ class StreamingCoordinator:
             # next writes
             for s in range(n_sides):
                 if n[s]:
-                    self._fold_device(si, rows[s], report, s)
+                    self._fold_device(si, rows[s], report, s, n[s])
                     rows[s] = np.zeros(shape, np.float32)
                     n[s] = 0
 
@@ -1287,7 +1303,7 @@ class StreamingCoordinator:
             self._admit_span(si, int(first[i]), int(last[i]), seen, ship,
                              fold_staged, report, side, kid, value, via=via)
         for s in range(n_sides):
-            self._fold_device(si, rows[s], report, s)
+            self._fold_device(si, rows[s], report, s, n[s])
 
     @lane("driver")
     def _ingest_host(self, si: int, recs, report: StreamReport,
@@ -1309,7 +1325,7 @@ class StreamingCoordinator:
                     slot = stage.tracker.slot_for(widx)
                 except LateEventError:
                     if n:
-                        self._fold_host(si, rows)
+                        self._fold_host(si, rows, n)
                         report.records_expanded += n
                         rows = np.zeros_like(rows)
                         n = 0
@@ -1322,7 +1338,7 @@ class StreamingCoordinator:
                 rows[n] = (slot, stage.tables[0].key_id(key), value, 1.0)
                 n += 1
         report.records_expanded += n
-        self._fold_host(si, rows)
+        self._fold_host(si, rows, n)
 
     @lane("driver")
     def _ingest_session(self, si: int, recs, report: StreamReport) -> None:
@@ -1343,7 +1359,7 @@ class StreamingCoordinator:
             nonlocal rows, n
             if n:
                 report.records_expanded += n
-                self._fold_host(si, rows)
+                self._fold_host(si, rows, n)
                 rows = np.zeros(shape, np.float32)
                 n = 0
 
@@ -1398,29 +1414,31 @@ class StreamingCoordinator:
         chains.  Reads only the immutable program, so the prefetch thread
         runs it for batch N+1 while the main thread folds batch N; the
         synchronous path calls it inline."""
-        prog = self.prog
-        if len(batch.records) > prog.batch_records:
-            raise ValueError(
-                f"micro-batch {batch.index} carries {len(batch.records)} "
-                f"records but the coordinator was sized for batch_records="
-                f"{prog.batch_records}; create the StreamSource with "
-                f"batch_records <= the coordinator's")
-        if len(prog.inputs) == 1:
-            # single-input fast path: no per-record re-tagging on the hot
-            # path (the input necessarily lands at stage 0, side 0)
-            groups: dict[int, list] = {0: batch.records}
-        else:
-            groups = {}
-            for rec in batch.records:
-                tag = int(rec[3]) if len(rec) > 3 else 0
-                si, side = prog.inputs[tag]
-                groups.setdefault(si, []).append(
-                    (rec[0], rec[1], rec[2], side))
-        return _PreparedBatch(
-            index=batch.index, n_records=len(batch.records),
-            max_event_time=batch.max_event_time,
-            groups={si: self._transform_recs(si, raw)
-                    for si, raw in groups.items()})
+        with tracing.span("coord.prepare", n=len(batch.records),
+                          key=f"{self.prog.job_id}/{batch.index}"):
+            prog = self.prog
+            if len(batch.records) > prog.batch_records:
+                raise ValueError(
+                    f"micro-batch {batch.index} carries {len(batch.records)} "
+                    f"records but the coordinator was sized for batch_records="
+                    f"{prog.batch_records}; create the StreamSource with "
+                    f"batch_records <= the coordinator's")
+            if len(prog.inputs) == 1:
+                # single-input fast path: no per-record re-tagging on the hot
+                # path (the input necessarily lands at stage 0, side 0)
+                groups: dict[int, list] = {0: batch.records}
+            else:
+                groups = {}
+                for rec in batch.records:
+                    tag = int(rec[3]) if len(rec) > 3 else 0
+                    si, side = prog.inputs[tag]
+                    groups.setdefault(si, []).append(
+                        (rec[0], rec[1], rec[2], side))
+            return _PreparedBatch(
+                index=batch.index, n_records=len(batch.records),
+                max_event_time=batch.max_event_time,
+                groups={si: self._transform_recs(si, raw)
+                        for si, raw in groups.items()})
 
     def _process_prepared(self, prep: _PreparedBatch,
                           report: StreamReport) -> None:
@@ -1431,44 +1449,47 @@ class StreamingCoordinator:
         collective per batch per side; a batch that spans more windows
         than the ring holds (low event rate relative to batch size) folds
         and finalizes mid-batch instead of aborting."""
-        prog = self.prog
-        t0 = time.perf_counter()
-        self.bus.poll(self.consumer_group, TOPIC_STREAM_BATCH,
-                      timeout=0.01, max_records=1)
-        self._autoscale(report)
-        late_before = self._late_dropped()
-        report.records_in += prep.n_records
-        for si in sorted(prep.groups):
-            recs = prep.groups[si]
-            if not recs:
-                continue
-            self._grow_wire(si, recs)
-            stage = self.stages[si]
-            if stage.plan.is_session:
-                self._ingest_session(si, recs, report)
-            elif prog.fanout == "device":
-                self._ingest_device(si, recs, report)
-            else:
-                self._ingest_host(si, recs, report)
-        # every root shares the merged stream's event-time watermark (a
-        # multi-root join consumes one merged, side-tagged source)
-        for si in self._roots:
-            self._ext_wm[si] = max(self._ext_wm.get(si, _NEG_INF),
-                                   prep.max_event_time)
-            self._observe(si)
-        self._finalize_sweep(report, set(self._roots))
-        self._drain_stats(report)       # micro-batch barrier: lanes empty
-        report.late_dropped += self._late_dropped() - late_before
-        report.hash_collisions = self._total_collisions()
-        report.batches += 1
-        self._records_consumed += prep.n_records
-        # sparser checkpoints trade restart replay (the log is replayable
-        # from the last checkpoint) for hot-path device syncs; interval 0
-        # disables checkpointing entirely (the batch-mode drive)
-        if self._ckpt_interval and \
-                (prep.index + 1) % self._ckpt_interval == 0:
-            self.save_state()
-        report.batch_latencies.append(time.perf_counter() - t0)
+        with tracing.span("coord.fold_drain", n=prep.n_records,
+                          key=f"{self.prog.job_id}/{prep.index}"):
+            prog = self.prog
+            t0 = time.perf_counter()
+            with tracing.span("coord.trigger_poll", key=self.prog.job_id):
+                self.bus.poll(self.consumer_group, TOPIC_STREAM_BATCH,
+                              timeout=0.01, max_records=1)
+            self._autoscale(report)
+            late_before = self._late_dropped()
+            report.records_in += prep.n_records
+            for si in sorted(prep.groups):
+                recs = prep.groups[si]
+                if not recs:
+                    continue
+                self._grow_wire(si, recs)
+                stage = self.stages[si]
+                if stage.plan.is_session:
+                    self._ingest_session(si, recs, report)
+                elif prog.fanout == "device":
+                    self._ingest_device(si, recs, report)
+                else:
+                    self._ingest_host(si, recs, report)
+            # every root shares the merged stream's event-time watermark (a
+            # multi-root join consumes one merged, side-tagged source)
+            for si in self._roots:
+                self._ext_wm[si] = max(self._ext_wm.get(si, _NEG_INF),
+                                       prep.max_event_time)
+                self._observe(si)
+            self._finalize_sweep(report, set(self._roots))
+            self._drain_stats(report)       # micro-batch barrier: lanes empty
+            report.late_dropped += self._late_dropped() - late_before
+            report.hash_collisions = self._total_collisions()
+            report.batches += 1
+            self._records_consumed += prep.n_records
+            # sparser checkpoints trade restart replay (the log is replayable
+            # from the last checkpoint) for hot-path device syncs; interval 0
+            # disables checkpointing entirely (the batch-mode drive)
+            if self._ckpt_interval and \
+                    (prep.index + 1) % self._ckpt_interval == 0:
+                self.save_state()
+            report.batch_latencies.append(time.perf_counter() - t0)
 
     def process_batch(self, batch: MicroBatch,
                       report: StreamReport) -> None:

@@ -15,10 +15,12 @@ in-memory tests/benchmarks, which skips storage entirely).
 from __future__ import annotations
 
 import io
+import itertools
 import json
 from dataclasses import dataclass
 from typing import Any, Iterable, Iterator
 
+from ..core import tracing
 from ..core.storage import ObjectStore
 
 
@@ -77,6 +79,10 @@ class MicroBatch:
 class StreamSource:
     """Chunk a persisted (or in-memory) event log into micro-batches."""
 
+    #: the span timing each micro-batch's read (None: the reads of the
+    #: log's segments are spans of their own)
+    read_span: str | None = None
+
     def __init__(self, store: ObjectStore | None = None, prefix: str = "",
                  records: Iterable[tuple[float, Any, float]] | None = None,
                  batch_records: int = 1024) -> None:
@@ -109,35 +115,44 @@ class StreamSource:
             return int(tail[1:])
         return None
 
-    def _events_from(self, skip: int) -> Iterator[tuple[float, Any, float]]:
-        """Records in log order, skipping the first ``skip`` cheaply:
-        store-backed logs drop whole already-consumed segments by their
-        key-embedded record counts, without downloading them."""
+    def segments_from(self, skip: int) -> Iterator[tuple[str | None, list]]:
+        """The log from record ``skip`` on as ``(segment key, records)``,
+        one pair per segment (one keyless pair for an in-memory log) — the
+        shared-ingest pump's tail read.  Store-backed logs drop whole
+        already-consumed segments by their key-embedded record counts,
+        without downloading them; a segment is decoded whole before it is
+        handed on (the ``ingest.fetch`` and ``ingest.decode`` spans)."""
         if self._records is not None:
-            yield from self._records[skip:]
+            if skip < len(self._records):
+                yield None, self._records[skip:]
             return
-        for seg in self.segments():
+        with tracing.span("ingest.fetch", key=self.prefix):
+            segments = self.segments()
+        for seg in segments:
             count = self._segment_count(seg)
             if count is not None and skip >= count:
                 skip -= count
                 continue
-            lines = [ln for ln in self.store.get(seg).splitlines() if ln]
-            if skip >= len(lines):
-                skip -= len(lines)
-                continue
-            for line in lines[skip:]:
-                ts, key, value = json.loads(line)
-                yield float(ts), key, float(value)
-            skip = 0
+            with tracing.span("ingest.fetch", key=seg) as fetch:
+                blob = self.store.get(seg)
+                fetch.n = len(blob)
+            with tracing.span("ingest.decode", key=seg) as decode:
+                lines = [ln for ln in blob.splitlines() if ln]
+                records = [(float(ts), key, float(value)) for ts, key, value
+                           in map(json.loads, lines[skip:])]
+                decode.n = len(records)
+            skip = max(0, skip - len(lines))
+            if records:
+                yield seg, records
+
+    def _events_from(self, skip: int) -> Iterator[tuple[float, Any, float]]:
+        """Records in log order, skipping the first ``skip`` cheaply."""
+        for _seg, records in self.segments_from(skip):
+            yield from records
 
     def events(self) -> Iterator[tuple[float, Any, float]]:
         """Every record in log order — a fresh, replayable pass."""
         return self._events_from(0)
-
-    def events_from(self, skip: int) -> Iterator[tuple[float, Any, float]]:
-        """Records from offset ``skip`` on, skipping consumed segments
-        without downloading them — the shared-ingest pump's tail read."""
-        return self._events_from(skip)
 
     def batch_sizes(self, start_record: int = 0) -> list[int]:
         """Per-batch record counts from metadata alone — key-embedded
@@ -169,15 +184,15 @@ class StreamSource:
         StreamingCoordinator passes its checkpointed *record* offset, so
         chunk boundaries cannot drift when the log has grown past a
         previously-partial final batch.  Batch indices restart at 0 for each
-        iteration — they identify batches within one run.
+        iteration — they identify batches within one run.  Each batch's
+        read is one ``read_span`` (if the class names one), closed before
+        the batch is handed on.
         """
-        chunk: list = []
-        index = 0
-        for rec in self._events_from(start_record):
-            chunk.append(rec)
-            if len(chunk) >= self.batch_records:
-                yield MicroBatch(index, chunk)
-                index += 1
-                chunk = []
-        if chunk:
+        records = self._events_from(start_record)
+        for index in itertools.count():
+            with tracing.span(self.read_span, key=index) as read:
+                chunk = list(itertools.islice(records, self.batch_records))
+                read.n = len(chunk)
+            if not chunk:
+                return
             yield MicroBatch(index, chunk)
