@@ -14,6 +14,11 @@ keeps the same shape in-process:
 
 CloudEvent envelope fields follow the CloudEvents 1.0 spec attributes the
 paper's Knative JobSinks consume (id, source, type, subject, data).
+CloudEvents carry the control plane only: triggers, batch announcements,
+windows, lifecycle and status.  A shared ingest's materialized data
+record is a plain :class:`Record` whose value is the immutable tuple
+``(ts, key, value, seq)``, appended a segment at a time per partition by
+:meth:`EventBus.produce_batch`.
 """
 
 from __future__ import annotations
@@ -23,7 +28,8 @@ import threading
 import time
 import uuid
 from dataclasses import dataclass, field
-from typing import Any
+from types import MappingProxyType
+from typing import Any, Mapping, Sequence
 
 
 @dataclass
@@ -38,25 +44,28 @@ class CloudEvent:
     time: float = field(default_factory=time.time)
 
 
+NO_HEADERS: Mapping[str, str] = MappingProxyType({})
+
+
 @dataclass
 class Record:
+    """One log entry.  ``value`` is a :class:`CloudEvent` on control
+    topics and the tuple ``(ts, key, value, seq)`` on a shared ingest's
+    materialized topic; records produced without headers share the one
+    read-only empty mapping."""
+
     key: str | None
-    value: CloudEvent
+    value: Any
     timestamp: float
     offset: int
     partition: int
-    headers: dict[str, str] = field(default_factory=dict)
+    headers: Mapping[str, str] = NO_HEADERS
 
 
 class _Partition:
     def __init__(self) -> None:
         self.log: list[Record] = []
         self.cond = threading.Condition()
-
-    def append(self, rec: Record) -> None:
-        with self.cond:
-            self.log.append(rec)
-            self.cond.notify_all()
 
 
 class Topic:
@@ -109,6 +118,27 @@ class EventBus:
             part.cond.notify_all()
         self.produced += 1
         return rec
+
+    def produce_batch(self, topic: str, partition: int,
+                      keys: Sequence[str | None], values: Sequence[Any],
+                      timestamp: float) -> int:
+        """Append ``values`` (``keys[i]`` is ``values[i]``'s key) to one
+        partition as consecutive records stamped ``timestamp``: one lock,
+        one ``extend`` and one wake-up for the whole batch.  The caller has
+        routed every key to ``partition``.  Returns the first record's
+        offset; offsets stay dense, as ``len(values)`` calls of ``produce``
+        would leave them."""
+        if len(keys) != len(values):
+            raise ValueError("produce_batch needs one key per value")
+        part = self.topic(topic).partitions[partition]
+        with part.cond:
+            first = len(part.log)
+            part.log.extend([Record(key, value, timestamp, offset, partition)
+                             for offset, key, value
+                             in zip(itertools.count(first), keys, values)])
+            part.cond.notify_all()
+        self.produced += len(values)
+        return first
 
     # -- consumer ------------------------------------------------------------
     def poll(self, group: str, topic: str, timeout: float = 1.0,
@@ -195,7 +225,8 @@ TOPIC_STREAM_WINDOW = "repro.stream.window"
 # transition (submitted/running/parked/…) on JOB_LIFECYCLE, and each
 # shared source materializes its one physical log read onto a private
 # ``repro.ingest.<source>`` topic that all subscribing jobs replay from
-# their own record cursors.
+# their own record cursors (plain ``(ts, key, value, seq)`` records, not
+# CloudEvents).
 TOPIC_JOB_LIFECYCLE = "repro.job.lifecycle"
 TOPIC_INGEST_PREFIX = "repro.ingest."
 

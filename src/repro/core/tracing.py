@@ -53,7 +53,7 @@ SPANS = (
     "ingest.pump",          # SharedIngest.pump; n = records materialized
     "ingest.fetch",         # segment listing, or one segment's GET; n = bytes
     "ingest.decode",        # one segment's lines parsed whole; n = records
-    "ingest.publish",       # one segment's envelopes + produce; n = records
+    "ingest.publish",       # one segment routed + batch-produced; n = records
     "topic.read",           # one micro-batch read off the topic; n = records
     "server.step",          # JobServer.step; n = records moved
     "server.lane_wait",     # overlapped driver blocked on a job's prefetch

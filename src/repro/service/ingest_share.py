@@ -19,7 +19,7 @@ Partitioning.  The topic may carry ``n_partitions`` partitions routed
 by record key (the bus's stable FNV-1a ``partition_for``), so parallel
 subscribers can each drain a disjoint partition subset of one source
 concurrently.  Determinism survives partitioning because every
-materialized event carries its global ``seq`` (the record's index in
+materialized record carries its global ``seq`` (the record's index in
 the physical log): a subscriber's view is the seq-sorted merge of its
 assigned partitions, which is a pure function of the log — independent
 of pump timing, partition interleaving, or crash/re-materialization.
@@ -29,32 +29,36 @@ the equivalent per-(subscriber, partition) replay cursors — the prefix
 of length ``cursor`` always splits into the same per-partition
 prefixes, which is what makes replay exactly-once per partition across
 a crash/re-attach.
+
+The materialized record.  Each topic entry is a bus ``Record`` (offset,
+partition, string key) whose value is the immutable tuple ``(ts, key,
+value, seq)``: no envelope, id or header dict per record.  The pump
+appends a segment at a time, one ``EventBus.produce_batch`` per
+partition the segment touches, every record stamped with one clock read
+per segment.
 """
 
 from __future__ import annotations
 
 import heapq
+import time
 from itertools import islice
 from typing import Iterable, Iterator, Sequence
 
 from ..core import tracing
-from ..core.events import CloudEvent, EventBus, Record, ingest_topic
+from ..core.events import EventBus, Record, ingest_topic
 from ..core.storage import ObjectStore
 from ..streaming.source import StreamSource
 
 __all__ = ["SharedIngest", "SubscriberSource"]
 
-
-def _record_event(source_id: str, record: tuple, seq: int) -> CloudEvent:
-    """Materialized-record envelope: the ``(ts, key, value)`` triple plus
-    ``seq``, the record's global index in the physical log — the anchor
-    that lets any partition subset merge back into log order."""
-    return CloudEvent(type="repro.ingest.record", source=source_id,
-                      data={"record": list(record), "seq": seq})
+#: distinct keys the route memo holds before it starts over (a hashed key
+#: space is open, so the memo is bounded, not sized to it)
+ROUTE_MEMO_MAX = 1 << 16
 
 
 def _seq(rec: Record) -> int:
-    return rec.value.data["seq"]
+    return rec.value[3]
 
 
 class SharedIngest:
@@ -83,27 +87,52 @@ class SharedIngest:
         # create_topic returns the existing topic if someone else made it
         # first — adopt its width so cursors stay consistent
         self.n_partitions = len(topic.partitions)
+        self._partition_for = topic.partition_for
+        self._routes: dict[str, int] = {}   # key -> partition, bounded
         self.pumped = 0          # records materialized so far
         self.pumps = 0           # physical tail reads taken
+        self.route_misses = 0    # FNV-1a routes computed (memo misses)
         self.subscribers: dict[str, "SubscriberSource"] = {}
+
+    def _route(self, key: str) -> int:
+        """A memo miss: the topic's FNV-1a route for ``key``, remembered.
+        The memo's hit share is ``1 - route_misses / pumped``."""
+        if len(self._routes) >= ROUTE_MEMO_MAX:
+            self._routes.clear()
+        p = self._routes[key] = self._partition_for(key)
+        self.route_misses += 1
+        return p
 
     # -- the one physical read ----------------------------------------------
     def pump(self) -> int:
         """Materialize the log's unread tail onto the topic — the only
         place the physical log is ever read, however many jobs subscribe.
-        Records are routed to partitions by record key (stable FNV-1a, so
-        a crashed server re-materializes the identical layout) and carry
-        their global ``seq``.  Returns new records appended."""
+        Records are routed to partitions by record key (stable FNV-1a,
+        memoised per key, so a crashed server re-materializes the
+        identical layout) and carry their global ``seq``; each segment
+        lands as one batch per partition it touches.  Returns new records
+        appended."""
         n = 0
+        routes = self._routes
         with tracing.span("ingest.pump", key=self.source_id) as pump:
             for seg, records in self.source.segments_from(self.pumped):
                 with tracing.span("ingest.publish", n=len(records), key=seg):
-                    for rec in records:
-                        self.bus.produce(self.topic,
-                                         _record_event(self.source_id, rec,
-                                                       self.pumped + n),
-                                         key=str(rec[1]))
-                        n += 1
+                    keys: list[list] = [[] for _ in range(self.n_partitions)]
+                    values: list[list] = [[] for _ in range(self.n_partitions)]
+                    for seq, (ts, key, value) in enumerate(records,
+                                                           self.pumped + n):
+                        skey = str(key)
+                        p = routes.get(skey)
+                        if p is None:
+                            p = self._route(skey)
+                        keys[p].append(skey)
+                        values[p].append((ts, key, value, seq))
+                    stamp = time.time()
+                    for p, part_keys in enumerate(keys):
+                        if part_keys:
+                            self.bus.produce_batch(self.topic, p, part_keys,
+                                                   values[p], stamp)
+                n += len(records)
             pump.n = n
         self.pumped += n
         self.pumps += 1
@@ -154,8 +183,7 @@ class SharedIngest:
             logs = [self.bus.fetch(self.topic, p, 0) for p in parts]
             records = islice(heapq.merge(*logs, key=_seq), offset, None)
         for rec in records:
-            ts, key, value = rec.value.data["record"]
-            yield (ts, key, value)
+            yield rec.value[:3]
 
     def partition_cursors(self, cursor: int,
                           partitions: Sequence[int] | None = None,

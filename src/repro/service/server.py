@@ -567,6 +567,7 @@ class JobServer:
             "jobs": {jid: j.state for jid, j in self.jobs.items()},
             "pool": self.pool.stats(),
             "ingests": {key: {"pumped": ing.pumped, "pumps": ing.pumps,
+                              "route_misses": ing.route_misses,
                               "partitions": ing.n_partitions,
                               "subscribers": len(ing.subscribers)}
                         for key, ing in self.ingests.items()},
